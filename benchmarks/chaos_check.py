@@ -42,6 +42,7 @@ _SRC = os.path.abspath(
 def _chaos_gate() -> tuple[float, dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"  # fake CPU devices; the parent may hold a chip
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     t0 = time.perf_counter()
     r = subprocess.run(

@@ -22,6 +22,7 @@ _SRC = os.path.abspath(
 def run(smoke: bool = False):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"  # fake CPU devices; the parent may hold a chip
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.overlap_gate"],

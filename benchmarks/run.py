@@ -26,6 +26,8 @@ import traceback
 
 import inspect
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (
     adaptive_runtime,
     arena_check,
@@ -334,6 +336,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="fast analytic subset for CI")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.only:
         names = args.only.split(",")
     elif args.smoke:

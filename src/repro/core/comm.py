@@ -82,17 +82,6 @@ def world_size(axis_names: Sequence[str]) -> int | jax.Array:
     return lax.psum(1, tuple(axis_names))
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a manual mesh axis inside shard_map — via
-    ``lax.axis_size`` where available, ``jax.core.axis_frame`` on older
-    releases."""
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    import jax.core as _jc
-
-    return int(_jc.axis_frame(axis_name))
-
-
 def all_gather(x: jax.Array, axis_names: Sequence[str]) -> jax.Array:
     """Gather along a new leading axis; identity (adds axis of 1) if local."""
     if not axis_names:
@@ -110,7 +99,7 @@ def flat_axis_index(axis_names: Sequence[str]):
     shard ``w`` of every bucket slot)."""
     idx = lax.axis_index(axis_names[0])
     for ax in axis_names[1:]:
-        idx = idx * axis_size(ax) + lax.axis_index(ax)
+        idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
     return idx
 
 
@@ -135,7 +124,7 @@ def reduce_scatter(
 
     W = 1
     for a in axes:
-        W *= axis_size(a)
+        W *= lax.axis_size(a)
 
     def op(v, names):
         s = lax.psum_scatter(v, names, scatter_dimension=0, tiled=True)
@@ -231,7 +220,7 @@ class Compressor:
         world = 1
         for a in axis_names:
             try:
-                world *= axis_size(a)
+                world *= lax.axis_size(a)
             except Exception:  # not inside a mapping over `a`
                 world = 1
                 break

@@ -105,14 +105,14 @@ def sharded_param_allgather(
     axes (single worker).
     """
     from . import arena as ar
-    from .comm import all_gather_tiled, axis_size, flat_axis_index
+    from .comm import all_gather_tiled, flat_axis_index
 
     if not axis_names or schedule.plan is None:
         return params
     plan = schedule.plan
     W = 1
     for a in axis_names:
-        W *= axis_size(a)
+        W *= jax.lax.axis_size(a)
     layout = ar.build_layout(plan, align=W)
     treedef = jax.tree_util.tree_structure(params)
     leaves = jax.tree_util.tree_leaves(params)

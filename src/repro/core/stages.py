@@ -45,7 +45,6 @@ from .comm import (
     Compressor,
     SyncStats,
     all_gather,
-    axis_size,
     dense_bytes,
     flat_axis_index,
     pmean,
@@ -150,9 +149,10 @@ class WireCast(WireStage):
     def execute_segment(self, x, axis_names):
         """-> (synced_segment, residual_segment)."""
         if self.wire_dtype is not None and x.dtype != self.wire_dtype:
-            xw = x.astype(self.wire_dtype)
-            xm = pmean(xw, axis_names).astype(x.dtype)
-            return xm, x - xw.astype(x.dtype)
+            from ..kernels.ref import cast_error
+
+            xw, err = cast_error(x, self.wire_dtype)
+            return pmean(xw, axis_names).astype(x.dtype), err
         return pmean(x, axis_names), jnp.zeros_like(x)
 
     def __repr__(self):
@@ -341,9 +341,9 @@ class OkTopKRoute(WireStage):
             out = jnp.zeros(n, flat.dtype).at[idx].set(vals)
             return out, out
 
-        W = axis_size(axis_names[0])
+        W = lax.axis_size(axis_names[0])
         for ax in axis_names[1:]:
-            W *= axis_size(ax)
+            W *= lax.axis_size(ax)
         m, region_size, cap = self._geometry(n, self.ratio, W)
         n_pad = region_size * W
 
@@ -871,7 +871,7 @@ class SyncPipeline(Compressor):
             return reduce_scatter(view, axis_names)
         W = 1
         for a in axis_names:
-            W *= axis_size(a)
+            W *= lax.axis_size(a)
         shard = reduce_scatter(view, axis_names)
         start = flat_axis_index(axis_names) * (view.shape[0] // W)
         return lax.dynamic_update_slice(
@@ -903,7 +903,7 @@ class SyncPipeline(Compressor):
             return None, (resids if ef_on else None)
         W = 1
         for a in axis_names:
-            W *= axis_size(a)
+            W *= lax.axis_size(a)
         layout = ar.build_layout(
             plan, (b,),
             wire_dtype=(
@@ -1057,7 +1057,7 @@ class SyncPipeline(Compressor):
         W = 1
         if sharded:
             for a in axis_names:
-                W *= axis_size(a)
+                W *= lax.axis_size(a)
         layout = ar.build_layout(plan, sel, wire_dtype=wd, align=W)
 
         # ---- pack pass: one streaming traversal of the gradient ----------

@@ -15,14 +15,16 @@ fuses them into one HBM pass per segment:
 coarse filter is static per phase, paper SS III.A), so each compiled phase
 contains only the variant it needs.
 
-Layout: flat vectors viewed as (blocks, ELEMWISE_BLOCK) rows = 8x128 VPU
-tiles x 32; grid is 1-D over blocks.  Two outputs per block (wire value at
-the wire dtype, residual at the gradient dtype) stream back to HBM once.
+Layout: flat vectors viewed as (rows, 128) lanes and tiled in
+(block_rows, 128) blocks (``common.lane_call``; 16-row multiples where the
+wire is 16-bit); the coefficient is an SMEM scalar; grid is 1-D over
+blocks.  Two outputs per block (wire value at the wire dtype, residual at
+the gradient dtype) stream back to HBM once.
 
-Rounding note (same as ``ef_covap.ef_update``): the fused pass compiles
-``g + c*r`` to an FMA (single rounding) where the 2-op jnp reference rounds
-the product separately, so interpret mode cannot be bitwise-identical to
-``kernels.ref.pack_ef_cast_ref``.  The arena path therefore engages this
+Rounding note (same as ``ef_covap.ef_update``): where the fused pass
+compiles ``g + c*r`` to an FMA (single rounding) and the 2-op jnp reference
+rounds the product separately, interpret mode cannot be bitwise-identical
+to ``kernels.ref.pack_ef_cast_ref``.  The arena path therefore engages this
 kernel on TPU by default and on CPU only via the explicit
 ``use_pack_kernel=True`` compressor option; the CPU default is the ref
 formulation, which IS bitwise-identical to the arena-off legacy ops.
@@ -33,14 +35,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .common import ELEMWISE_BLOCK, INTERPRET, pad_to_multiple, unpad
+from .common import ELEMWISE_BLOCK, INTERPRET, lane_call
 
 
 def _kernel_selected_cast(wd):
     def kernel(g_ref, r_ref, coeff_ref, wire_ref, rnew_ref):
-        c = coeff_ref[0]
+        c = coeff_ref[0].astype(g_ref.dtype)
         t = g_ref[...] + c * r_ref[...]
         w = t.astype(wd)
         wire_ref[...] = w
@@ -50,14 +51,14 @@ def _kernel_selected_cast(wd):
 
 
 def _kernel_selected(g_ref, r_ref, coeff_ref, wire_ref, rnew_ref):
-    c = coeff_ref[0]
+    c = coeff_ref[0].astype(g_ref.dtype)
     t = g_ref[...] + c * r_ref[...]
     wire_ref[...] = t
     rnew_ref[...] = jnp.zeros_like(t)
 
 
 def _kernel_unselected(g_ref, r_ref, coeff_ref, wire_ref, rnew_ref):
-    c = coeff_ref[0]
+    c = coeff_ref[0].astype(g_ref.dtype)
     t = g_ref[...] + c * r_ref[...]
     wire_ref[...] = jnp.zeros_like(wire_ref[...])
     rnew_ref[...] = t
@@ -83,12 +84,7 @@ def pack_ef_cast(
     assert g.ndim == 1 and g.shape == r.shape
     wd = jnp.dtype(wire_dtype) if wire_dtype is not None else jnp.dtype(g.dtype)
     cast = wd != g.dtype
-    gp, n = pad_to_multiple(g, block)
-    rp, _ = pad_to_multiple(r, block)
-    nblocks = gp.shape[0] // block
-    g2 = gp.reshape(nblocks, block)
-    r2 = rp.reshape(nblocks, block)
-    coeff_arr = jnp.asarray(coeff, g.dtype).reshape(1)
+    coeff_arr = jnp.asarray(coeff, jnp.float32).reshape(1)
 
     if not selected:
         kernel = _kernel_unselected
@@ -96,22 +92,7 @@ def pack_ef_cast(
         kernel = _kernel_selected_cast(wd)
     else:
         kernel = _kernel_selected
-    wire, rnew = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(g2.shape, wd),
-            jax.ShapeDtypeStruct(r2.shape, r.dtype),
-        ],
-        interpret=interpret,
-    )(g2, r2, coeff_arr)
-    return unpad(wire.reshape(-1), n), unpad(rnew.reshape(-1), n)
+    return lane_call(
+        kernel, (g, r), (wd, r.dtype),
+        block=block, interpret=interpret, smem_in=(coeff_arr,),
+    )

@@ -5,7 +5,8 @@ float8_e4m3fn gives 4x wire compression with far better fidelity than
 naive casting.  One fused pass computes the block amax (VPU reduction in
 VMEM) and writes the scaled fp8 payload + per-block scale.
 
-Block = one (8x128)-aligned tile row of ``block`` elements.
+Block = ``block`` contiguous elements, one (block/128, 128) lane tile
+(``common.lane_call``); the per-block scales live in SMEM.
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import INTERPRET, pad_to_multiple, unpad
+from .common import INTERPRET, lane_call
 
 FP8_MAX = 448.0  # float8_e4m3fn max finite
 
@@ -25,53 +26,29 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     amax = jnp.max(jnp.abs(x))
     scale = jnp.maximum(amax / FP8_MAX, 1e-12)
     q_ref[...] = (x / scale).astype(jnp.float8_e4m3fn)
-    s_ref[0] = scale.astype(jnp.float32)
+    s_ref[pl.program_id(0)] = scale.astype(jnp.float32)
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0]
+    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[pl.program_id(0)]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def quantize_fp8(x: jax.Array, *, block: int = 8192, interpret: bool | None = None):
     """x: (N,) fp32/bf16 -> (q (N,) fp8, scales (nblocks,) fp32)."""
     interpret = INTERPRET if interpret is None else interpret
-    xp, n = pad_to_multiple(x, block)
-    nb = xp.shape[0] // block
-    x2 = xp.reshape(nb, block)
-    q, s = pl.pallas_call(
-        _quant_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x2.shape, jnp.float8_e4m3fn),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2)
-    return unpad(q.reshape(-1), n), s
+    return lane_call(
+        _quant_kernel, (x,), (jnp.float8_e4m3fn,),
+        block=block, interpret=interpret, smem_out=(jnp.float32,),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def dequantize_fp8(q: jax.Array, scales: jax.Array, *, block: int = 8192,
                    interpret: bool | None = None) -> jax.Array:
     interpret = INTERPRET if interpret is None else interpret
-    qp, n = pad_to_multiple(q, block)
-    nb = qp.shape[0] // block
-    q2 = qp.reshape(nb, block)
-    x = pl.pallas_call(
-        _dequant_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q2.shape, jnp.float32),
-        interpret=interpret,
-    )(q2, scales)
-    return unpad(x.reshape(-1), n)
+    (x,) = lane_call(
+        _dequant_kernel, (q,), (jnp.float32,),
+        block=block, interpret=interpret, smem_in=(scales,),
+    )
+    return x

@@ -7,6 +7,19 @@ import jax.numpy as jnp
 FP8_MAX = 448.0
 
 
+def cast_error(t, wire_dtype):
+    """``(w, t - w)`` with ``w = t.astype(wire_dtype)``: the wire value and
+    the rounding error it left behind, exact.
+
+    Under XLA's default excess-precision rule the TPU backend may skip the
+    narrowing cast when its result is widened again inside one fusion; on a
+    v5e ``t - t.astype(bf16).astype(f32)`` came out all zeros, so error
+    feedback lost the cast error.  The barrier makes ``w`` a real narrow
+    value before it is widened."""
+    w = t.astype(wire_dtype)
+    return w, t - jax.lax.optimization_barrier(w).astype(t.dtype)
+
+
 def ef_update_ref(g, r, coeff, *, selected: bool):
     t = g + jnp.asarray(coeff, g.dtype) * r
     if selected:
@@ -42,8 +55,7 @@ def pack_ef_cast_ref(g, r, coeff, *, selected: bool, wire_dtype=None):
         return zero, (t if r is not None else None)
     if wd is None or t.dtype == wd:
         return t, (jnp.zeros_like(t) if r is not None else None)
-    w = t.astype(wd)
-    rnew = t - w.astype(t.dtype)
+    w, rnew = cast_error(t, wd)
     return w, (rnew if r is not None else None)
 
 

@@ -9,13 +9,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import ELEMWISE_BLOCK, INTERPRET, pad_to_multiple, unpad
+from .common import ELEMWISE_BLOCK, INTERPRET, lane_call
 
 
 def _sign_kernel(x_ref, s_ref, a_ref):
     x = x_ref[...].astype(jnp.float32)
     s_ref[...] = jnp.where(x >= 0, 1, -1).astype(jnp.int8)
-    a_ref[0] = jnp.sum(jnp.abs(x))
+    a_ref[pl.program_id(0)] = jnp.sum(jnp.abs(x))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -23,25 +23,13 @@ def sign_compress(x: jax.Array, *, block: int = ELEMWISE_BLOCK,
                   interpret: bool | None = None):
     """x: (N,) -> (signs (N,) int8, scale () fp32 = mean|x|)."""
     interpret = INTERPRET if interpret is None else interpret
-    xp, n = pad_to_multiple(x, block)
-    nb = xp.shape[0] // block
-    x2 = xp.reshape(nb, block)
-    signs, partials = pl.pallas_call(
-        _sign_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x2.shape, jnp.int8),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2)
+    n = x.shape[0]
+    signs, partials = lane_call(
+        _sign_kernel, (x,), (jnp.int8,),
+        block=block, interpret=interpret, smem_out=(jnp.float32,),
+    )
     scale = jnp.sum(partials) / jnp.float32(max(n, 1))
-    return unpad(signs.reshape(-1), n), scale
+    return signs, scale
 
 
 def sign_decompress(signs: jax.Array, scale: jax.Array) -> jax.Array:

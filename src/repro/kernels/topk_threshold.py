@@ -13,14 +13,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import ELEMWISE_BLOCK, INTERPRET, pad_to_multiple, unpad
+from .common import ELEMWISE_BLOCK, INTERPRET, lane_call
 
 
 def _thresh_kernel(x_ref, t_ref, y_ref, c_ref):
     x = x_ref[...]
-    keep = jnp.abs(x) >= t_ref[0]
+    keep = jnp.abs(x) >= t_ref[0].astype(x.dtype)
     y_ref[...] = jnp.where(keep, x, jnp.zeros_like(x))
-    c_ref[0] = jnp.sum(keep.astype(jnp.int32))
+    c_ref[pl.program_id(0)] = jnp.sum(keep.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -29,28 +29,13 @@ def threshold_filter(x: jax.Array, threshold: jax.Array, *,
                      interpret: bool | None = None):
     """x: (N,) -> (masked (N,), counts (nblocks,) int32)."""
     interpret = INTERPRET if interpret is None else interpret
-    xp, n = pad_to_multiple(x, block)
-    nb = xp.shape[0] // block
-    x2 = xp.reshape(nb, block)
-    t = jnp.asarray(threshold, x.dtype).reshape(1)
-    y, c = pl.pallas_call(
-        _thresh_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x2.shape, x.dtype),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x2, t)
-    return unpad(y.reshape(-1), n), c
+    # SMEM holds 32-bit words: the threshold travels as f32 (exact for the
+    # f32/bf16 values it is compared with) and is cast back in the kernel
+    t = jnp.asarray(threshold, x.dtype).astype(jnp.float32).reshape(1)
+    return lane_call(
+        _thresh_kernel, (x,), (x.dtype,),
+        block=block, interpret=interpret, smem_in=(t,), smem_out=(jnp.int32,),
+    )
 
 
 def sample_threshold(x: jax.Array, ratio: float, sample: int = 4096) -> jax.Array:

@@ -1,5 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
+# compile-only CPU tool: fake 512 host devices, never an accelerator
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "")
+    + " --xla_force_host_platform_device_count=512"
+).strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run (deliverable e).
 

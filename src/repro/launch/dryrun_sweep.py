@@ -33,7 +33,7 @@ def run_combo(arch, shape, mesh_tag, compressor, interval, out_dir, timeout):
     try:
         proc = subprocess.run(
             cmd, capture_output=True, text=True, timeout=timeout,
-            env={**os.environ},
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},  # compile-only
         )
         crashed = proc.returncode != 0 and not os.path.exists(path)
         if crashed:

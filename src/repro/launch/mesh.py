@@ -10,28 +10,19 @@ state (the dry-run sets XLA_FLAGS before any jax import).
 """
 from __future__ import annotations
 
-import numpy as np
-
 import jax
 
 
-def make_mesh_compat(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh`` with ``axis_types`` is a newer-jax API; older
-    releases build a ``Mesh`` from a device array directly.  All axes are
-    Auto either way."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    n = int(np.prod(shape))
-    devices = np.array(jax.devices()[:n]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _mesh(shape, axes)
 
 
 def dp_axes(*, multi_pod: bool = False) -> tuple[str, ...]:
@@ -51,10 +42,10 @@ def make_slice_mesh(n_slices: int, data: int = 8, model: int = 8):
     ``launch.hlo_analysis.group_link`` assumes.  ``n_slices <= 1``
     degenerates to the flat (data, model) mesh."""
     if n_slices <= 1:
-        return make_mesh_compat((data, model), ("data", "model"))
-    return make_mesh_compat((n_slices, data, model), ("pod", "data", "model"))
+        return _mesh((data, model), ("data", "model"))
+    return _mesh((n_slices, data, model), ("pod", "data", "model"))
 
 
 def make_test_mesh(data: int = 4, model: int = 2):
     """Small mesh for multi-device CPU tests (spawned with fake devices)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))

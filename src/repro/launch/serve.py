@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import Engine, ServeConfig, TrafficConfig, run_traffic, sweep
 
@@ -61,6 +62,7 @@ def main():
                          "per-request spans, stage histograms and queue/"
                          "page-pool series into this directory")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build_model(cfg)

@@ -21,6 +21,7 @@ from repro import checkpoint
 from repro.api import resolve_interval
 from repro.configs import get_config, get_reduced
 from repro.data import DataConfig, make_loader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import adamw, cosine_warmup, sgd
 from repro.train.trainer import TrainConfig, Trainer
@@ -92,6 +93,7 @@ def main():
                          "writes events.jsonl (streamed), metrics.prom, "
                          "metrics.json and trace.json into this directory")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.interval == "adaptive":
         # mirror repro.api.fit: interval="adaptive" = analytic initial
         # pick + the online runtime armed

@@ -280,12 +280,11 @@ def build_schedule_only_fn(schedule, *, mesh=None, dp_axes: Sequence[str] = ()):
     if mesh is not None and dp_axes:
         from jax.sharding import PartitionSpec as P
 
-        from repro.train.trainer import shard_map_compat
-
-        mapped = shard_map_compat(
-            body, mesh,
-            tuple(P() for _ in shapes), tuple(P() for _ in shapes),
-            tuple(dp_axes),
+        mapped = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=tuple(P() for _ in shapes),
+            out_specs=tuple(P() for _ in shapes),
+            axis_names=set(dp_axes), check_vma=False,
         )
         jitted = jax.jit(mapped)
     else:

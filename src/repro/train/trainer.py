@@ -39,30 +39,6 @@ from repro.core.schedule import CollectiveCall, CommSchedule, mean_bytes_per_ste
 from repro.optim import Optimizer, apply_updates, clip_by_global_norm, global_norm
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs, axis_names):
-    """``jax.shard_map`` appeared (with ``check_vma``) in newer jax; older
-    releases ship ``jax.experimental.shard_map`` (with ``check_rep``).  The
-    trainer supports both so CPU dry-runs work on either toolchain."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(axis_names), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # NOTE: unlike jax.shard_map(axis_names=...), the experimental API
-    # treats every mesh axis as manual here.  Passing auto= for the
-    # non-DP axes would match the new API's manual/auto split, but
-    # partial-manual shard_map CHECK-fails in the old XLA builds this
-    # fallback targets (hlo_sharding_util: IsManualSubgroup) — so on old
-    # jax the model axis runs replicated (correct numerics, no TP
-    # sharding of the step's math).  The production TP path requires a
-    # jax with jax.shard_map.
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 @dataclasses.dataclass(frozen=True)
 class TrainConfig:
     compressor: str = "covap"
@@ -245,7 +221,7 @@ def pod_reconcile(params, schedule: CommSchedule, *,
     from repro.core import arena as ar
     from repro.core import bucketing as bk
     from repro.core.comm import (
-        all_gather_tiled, axis_size, flat_axis_index, pod_shard_exchange,
+        all_gather_tiled, flat_axis_index, pod_shard_exchange,
     )
 
     plan = schedule.plan
@@ -254,7 +230,7 @@ def pod_reconcile(params, schedule: CommSchedule, *,
     helper = tuple(reconcile_helper_axes)
     W = 1
     for a in helper:
-        W *= axis_size(a)
+        W *= lax.axis_size(a)
     if not schedule.selected:
         return params, schedule.bytes_per_worker
     layout = ar.build_layout(plan, schedule.selected, align=W)
@@ -525,18 +501,19 @@ def build_train_step(
 
     state_spec = P("pod") if hier else P()
     batch_spec = P(tuple(dp_axes))
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         step_fn,
-        mesh,
-        (
+        mesh=mesh,
+        in_specs=(
             state_spec,                           # params
             state_spec,                           # opt_state
             state_spec,                           # comp_state (residuals)
             batch_spec,                           # batch (sharded on dim 0)
             P(),                                  # step
         ),
-        (state_spec, state_spec, state_spec, P()),
-        dp_axes,
+        out_specs=(state_spec, state_spec, state_spec, P()),
+        axis_names=set(dp_axes),
+        check_vma=False,
     )
     kw = {}
     if param_shardings is not None:
@@ -765,8 +742,10 @@ class Trainer:
                 return out
 
             spec = P("pod") if hier else P()
-            mapped = shard_map_compat(
-                flush, self.mesh, (spec, spec), (spec, spec), self.dp_axes
+            mapped = jax.shard_map(
+                flush, mesh=self.mesh, in_specs=(spec, spec),
+                out_specs=(spec, spec), axis_names=set(self.dp_axes),
+                check_vma=False,
             )
             self._flush_fns[0] = jax.jit(mapped)
         return self._flush_fns[0]

@@ -100,7 +100,6 @@ def test_measure_ccr_on_cpu_mesh():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.ccr import measure_ccr
-from repro.train.trainer import shard_map_compat
 
 mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
 x = jnp.arange(8 * 4096, dtype=jnp.float32).reshape(8, 4096)
@@ -112,8 +111,8 @@ def full_worker(x):
 def comp_worker(x):
     return jnp.tanh(x) @ jnp.ones((x.shape[-1], 64))
 
-full = jax.jit(shard_map_compat(full_worker, mesh, (P("data"),), P(), ("data",)))
-comp = jax.jit(shard_map_compat(comp_worker, mesh, (P("data"),), P("data"), ("data",)))
+full = jax.jit(jax.shard_map(full_worker, mesh=mesh, in_specs=(P("data"),), out_specs=P(), axis_names={"data"}, check_vma=False))
+comp = jax.jit(jax.shard_map(comp_worker, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"), axis_names={"data"}, check_vma=False))
 
 res = measure_ccr(
     lambda: jax.block_until_ready(full(x)),

@@ -129,7 +129,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import build_plan, get_compressor
 from repro.launch.hlo_analysis import collective_bytes_per_worker
-from repro.train.trainer import shard_map_compat
 
 W = 8
 mesh = Mesh(np.array(jax.devices()[:W]), ("data",))
@@ -163,8 +162,9 @@ for name, opts, phase in CASES:
         out, s2, _ = comp.execute(sched, g, s, step=0, axis_names=("data",))
         return out, s2
 
-    f = jax.jit(shard_map_compat(
-        run, mesh, (P("data"), P()), (P(), P()), ("data",)))
+    f = jax.jit(jax.shard_map(
+        run, mesh=mesh, in_specs=(P("data"), P()), out_specs=(P(), P()),
+        axis_names={"data"}, check_vma=False))
     hlo = f.lower(gw, state).compile().as_text()
     got = collective_bytes_per_worker(hlo, W)
     # The CPU backend widens narrow wire formats inside collectives
@@ -206,7 +206,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import build_plan, get_compressor
 from repro.core.overlap import sharded_param_allgather
 from repro.launch.hlo_analysis import collective_bytes_per_worker, parse_collectives
-from repro.train.trainer import shard_map_compat
 
 W = 8
 mesh = Mesh(np.array(jax.devices()[:W]), ("data",))
@@ -233,8 +232,9 @@ for name, opts, phase in CASES:
         out, s2, _ = comp.execute(sched, g, s, step=0, axis_names=("data",))
         return out, s2
 
-    f = jax.jit(shard_map_compat(
-        run, mesh, (P("data"), P()), (P(), P()), ("data",)))
+    f = jax.jit(jax.shard_map(
+        run, mesh=mesh, in_specs=(P("data"), P()), out_specs=(P(), P()),
+        axis_names={"data"}, check_vma=False))
     hlo = f.lower(gw, state).compile().as_text()
     got = collective_bytes_per_worker(hlo, W)
     kinds = {o.kind for o in parse_collectives(hlo)}
@@ -254,7 +254,8 @@ for name, opts, phase in CASES:
     def head(p):
         return sharded_param_allgather(comp, sched, p, axis_names=("data",))
 
-    fh = jax.jit(shard_map_compat(head, mesh, (P(),), P(), ("data",)))
+    fh = jax.jit(jax.shard_map(head, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                               axis_names={"data"}, check_vma=False))
     hlo_h = fh.lower(params).compile().as_text()
     got_h = collective_bytes_per_worker(hlo_h, W)
     kinds_h = {o.kind for o in parse_collectives(hlo_h)}

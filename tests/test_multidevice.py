@@ -27,7 +27,6 @@ PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import build_plan, get_compressor
-from repro.train.trainer import shard_map_compat
 
 mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
 params = {"w": jnp.zeros((64, 16)), "b": jnp.zeros((16,))}
@@ -53,8 +52,9 @@ for name in ("none", "covap", "fp16", "randomk"):
         out, s2, _ = comp.sync(g, s, plan=plan, phase=0, step=0,
                                axis_names=("data",))
         return out
-    f = jax.jit(shard_map_compat(sync_worker, mesh,
-        (P("data"), P()), P(), ("data",)))
+    f = jax.jit(jax.shard_map(sync_worker, mesh=mesh,
+        in_specs=(P("data"), P()), out_specs=P(), axis_names={"data"},
+        check_vma=False))
     got = f(gw, state)
     mean = {k: v.mean(axis=0) for k, v in gw.items()}
     # compare only where the scheme communicated (out != 0)
@@ -80,8 +80,9 @@ for name in ("topk", "efsignsgd", "oktopk", "fp8wire"):
         out, s2, _ = comp.sync(g, s, plan=plan, phase=0, step=0,
                                axis_names=("data",))
         return out
-    f = jax.jit(shard_map_compat(sync_worker, mesh,
-        (P("data"), P()), P(), ("data",)))
+    f = jax.jit(jax.shard_map(sync_worker, mesh=mesh,
+        in_specs=(P("data"), P()), out_specs=P(), axis_names={"data"},
+        check_vma=False))
     got = f(gw, state)
     for k in got:
         assert bool(jnp.all(jnp.isfinite(got[k]))), name

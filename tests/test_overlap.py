@@ -255,8 +255,8 @@ for compressor in ("covap", "none"):
 # hierarchical pods: fused == post numerically (XLA fusion choices may
 # differ at the ulp level between the two programs; bitwise pinning is a
 # pure-DP-mesh property)
-from repro.launch.mesh import make_mesh_compat
-hmesh = make_mesh_compat((2, 4), ("pod", "data"))
+hmesh = jax.make_mesh((2, 4), ("pod", "data"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 def run_hier(overlap, steps=4):
     tc = TrainConfig(compressor="covap", interval=2, pod_interval=2,
