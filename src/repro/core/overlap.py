@@ -281,7 +281,8 @@ def overlapped_loss_and_grads(
             hooked = install_hooks(
                 pipeline, schedule, p, r, coeff, axis_names=axis_names
             )
-            return model.loss_fn(hooked, batch)
+            with jax.named_scope("model"):
+                return model.loss_fn(hooked, batch)
 
         (loss, metrics), (synced, new_r) = jax.value_and_grad(
             lf, argnums=(0, 1), has_aux=True
@@ -292,7 +293,8 @@ def overlapped_loss_and_grads(
         hooked = install_hooks(
             pipeline, schedule, p, None, None, axis_names=axis_names
         )
-        return model.loss_fn(hooked, batch)
+        with jax.named_scope("model"):
+            return model.loss_fn(hooked, batch)
 
     (loss, metrics), synced = jax.value_and_grad(lf0, has_aux=True)(params)
     return loss, metrics, synced, comp_state

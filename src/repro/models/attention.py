@@ -92,9 +92,14 @@ def attn_train(params, x, cfg, *, window: int = 0) -> jax.Array:
         out = _scores_softmax_value(qc, k, v, m, cfg)
         return carry, out
 
-    q_chunks = q.reshape(B, n_chunks, chunk, K, G, hd).transpose(1, 0, 2, 3, 4, 5)
-    _, outs = lax.scan(body, (), (q_chunks, jnp.arange(n_chunks)))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H * hd)
+    # the scope holds what a fused attention kernel would replace: the
+    # chunked scores, softmax and value product with their layout moves;
+    # the QKV and output projections stay outside it
+    with jax.named_scope("attention"):
+        q_chunks = q.reshape(B, n_chunks, chunk, K, G, hd).transpose(
+            1, 0, 2, 3, 4, 5)
+        _, outs = lax.scan(body, (), (q_chunks, jnp.arange(n_chunks)))
+        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H * hd)
     cd = jnp.dtype(cfg.compute_dtype)
     return jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(cd))
 

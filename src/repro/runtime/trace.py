@@ -17,18 +17,16 @@ recovers mean ``t_comp`` / ``t_comm`` / effective link bandwidth from a
 trace dict, which plug straight into ``simulate_schedule`` — measurements
 calibrate the same model that produced the plan.
 
-Multi-worker timestamps go through ``core.ccr.align_comm_times`` before
-becoming spans, so rendezvous wait is excluded exactly as in the paper's
-distributed profiler (§III.B, Fig. 3).
+The measured rows sit on a synthetic clock (each step starts where the
+last one ended) and the planned rows on the perf model's; neither joins a
+device trace.  The device-clock timeline is the JAX profiler's: a
+``jax.profiler`` trace of ``Trainer.run`` holds the trainer's ``train.*``
+host spans and its named scopes beside the device ops.
 """
 from __future__ import annotations
 
 import json
-from typing import Any, Sequence
-
-import numpy as np
-
-from repro.core.ccr import align_comm_times
+from typing import Any
 
 # Chrome trace pids: one logical process per view
 PID_PLANNED = 1
@@ -96,32 +94,6 @@ class TimelineTracer:
             "comm", pid=PID_MEASURED, tid=1, ts_s=t0 + sample.t_comp,
             dur_s=sample.t_comm, cat="measured,comm", args=comm_args,
         )
-
-    def record_aligned_collectives(
-        self,
-        step: int,
-        names: Sequence[str],
-        starts: np.ndarray,
-        ends: np.ndarray,
-        *,
-        bytes_per_op: Sequence[int] | None = None,
-    ) -> None:
-        """Per-collective spans from (workers, ops) timestamp arrays, with
-        the paper's alignment applied: span start is the **last** worker's
-        arrival, duration the aligned transfer time."""
-        starts = np.asarray(starts, np.float64)
-        ends = np.asarray(ends, np.float64)
-        durs = align_comm_times(starts, ends)
-        t_start = starts.max(axis=0)
-        for i, name in enumerate(names):
-            args = {"step": step, "op": i}
-            if bytes_per_op is not None:
-                args["bytes"] = int(bytes_per_op[i])
-            self.add_event(
-                name, pid=PID_MEASURED, tid=2,
-                ts_s=float(t_start[i]), dur_s=float(max(durs[i], 0.0)),
-                cat="measured,collective", args=args,
-            )
 
     # ---- planned view -----------------------------------------------------
     def record_planned_phase(

@@ -93,7 +93,10 @@ def make_compressor(tc: TrainConfig) -> Compressor:
 
 def _loss_and_grads(model, params, batch):
     def lf(p):
-        loss, metrics = model.loss_fn(p, batch)
+        # the scope labels the forward ops ``jvp(model)`` and the backward
+        # ops ``transpose(jvp(model))`` in the HLO metadata and the profile
+        with jax.named_scope("model"):
+            loss, metrics = model.loss_fn(p, batch)
         return loss, metrics
 
     (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params)
@@ -415,20 +418,23 @@ def _build_phase_step(
                 comm_schedule, grads, comp_state,
                 step=step, axis_names=grad_axes,
             )
-        if sharded and grad_axes:
-            gnorm = _sharded_grad_norm(synced, grad_axes)
-            if clip_norm > 0:
-                scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-12))
-                synced = jax.tree.map(
-                    lambda x: (x.astype(jnp.float32) * scale).astype(x.dtype),
-                    synced,
-                )
-        elif clip_norm > 0:
-            synced, gnorm = clip_by_global_norm(synced, clip_norm)
-        else:
-            gnorm = global_norm(synced)
-        updates, opt_state = optimizer.update(synced, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            if sharded and grad_axes:
+                gnorm = _sharded_grad_norm(synced, grad_axes)
+                if clip_norm > 0:
+                    scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-12))
+                    synced = jax.tree.map(
+                        lambda x: (
+                            x.astype(jnp.float32) * scale
+                        ).astype(x.dtype),
+                        synced,
+                    )
+            elif clip_norm > 0:
+                synced, gnorm = clip_by_global_norm(synced, clip_norm)
+            else:
+                gnorm = global_norm(synced)
+            updates, opt_state = optimizer.update(synced, opt_state, params)
+            params = apply_updates(params, updates)
         if hier:
             params, _ = pod_reconcile(
                 params, pod_schedule,
@@ -921,66 +927,75 @@ class Trainer:
         )
         t0 = time.perf_counter()
         for i in range(steps):
-            batch = next(it)
-            if res is not None:
-                # snapshot (free: state dicts reference immutable arrays)
-                # -> guard-owned checkpoint -> fault injection
-                state, batch = res.pre_step(state, batch)
-            phase = state["step"] % self.num_phases
-            fn = self._phase_fn(phase)
-            # block for a true wall time only on probe-due steps — an
-            # every-step block would serialise async dispatch for the
-            # whole run to feed a diagnostic metric
-            timed = rt is not None and rt.due_next()
-            t_step = time.perf_counter() if timed else 0.0
-            params, opt, comp, metrics = fn(
-                state["params"], state["opt"], state["comp"], batch,
-                jnp.asarray(state["step"], jnp.int32),
-            )
-            state = {"params": params, "opt": opt, "comp": comp,
-                     "step": state["step"] + 1}
-            steps_c.inc()
-            if self.sharded:
-                self._pending_sync = True
-            if res is not None:
-                # guard check + recovery BEFORE the adaptive runtime sees
-                # the state: a poisoned step must not feed the CCR probe
-                # or cross a re-plan boundary
-                state = res.post_step(state, metrics)
-            if rt is not None:
-                wall = None
-                if timed:
-                    jax.block_until_ready(params)
-                    wall = time.perf_counter() - t_step
-                state = rt.after_step(state, batch, wall_s=wall, log=log)
-            if (i + 1) % self.tc.log_every == 0 or i == 0:
-                m = {k: float(v) for k, v in metrics.items()}
-                m["step"] = state["step"]
-                m["wall_s"] = time.perf_counter() - t0
-                self.history.append(m)
-                if tel.enabled:
-                    loss_g.set(m["total_loss"])
-                    gnorm_g.set(m["grad_norm"])
-                    tel.events.emit(
-                        "step",
-                        step=int(state["step"]),
-                        loss=m["total_loss"],
-                        grad_norm=m["grad_norm"],
-                        wall_s=m["wall_s"],
-                        phase=int(phase),
-                        metrics={
-                            k: v for k, v in m.items()
-                            if k not in ("step", "wall_s")
-                        },
+            # host spans on the profiler's clock: each step, the wait
+            # for its batch, its dispatch and the log-cadence host sync;
+            # next to free when no trace is being taken
+            with jax.profiler.StepTraceAnnotation(
+                "train_step", step_num=state["step"]
+            ):
+                with jax.profiler.TraceAnnotation("train.batch_wait"):
+                    batch = next(it)
+                if res is not None:
+                    # snapshot (free: state dicts reference immutable arrays)
+                    # -> guard-owned checkpoint -> fault injection
+                    state, batch = res.pre_step(state, batch)
+                phase = state["step"] % self.num_phases
+                fn = self._phase_fn(phase)
+                # block for a true wall time only on probe-due steps — an
+                # every-step block would serialise async dispatch for the
+                # whole run to feed a diagnostic metric
+                timed = rt is not None and rt.due_next()
+                t_step = time.perf_counter() if timed else 0.0
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    params, opt, comp, metrics = fn(
+                        state["params"], state["opt"], state["comp"], batch,
+                        jnp.asarray(state["step"], jnp.int32),
                     )
-                if log:
-                    # only total_loss/grad_norm are guaranteed — model
-                    # metrics dicts need not include a 'loss' key
-                    shown = m.get("loss", m["total_loss"])
-                    log(
-                        f"step {state['step']:>5d}  loss {shown:.4f}  "
-                        f"gnorm {m['grad_norm']:.3f}  t {m['wall_s']:.1f}s"
-                    )
+                state = {"params": params, "opt": opt, "comp": comp,
+                         "step": state["step"] + 1}
+                steps_c.inc()
+                if self.sharded:
+                    self._pending_sync = True
+                if res is not None:
+                    # guard check + recovery BEFORE the adaptive runtime sees
+                    # the state: a poisoned step must not feed the CCR probe
+                    # or cross a re-plan boundary
+                    state = res.post_step(state, metrics)
+                if rt is not None:
+                    wall = None
+                    if timed:
+                        jax.block_until_ready(params)
+                        wall = time.perf_counter() - t_step
+                    state = rt.after_step(state, batch, wall_s=wall, log=log)
+                if (i + 1) % self.tc.log_every == 0 or i == 0:
+                    with jax.profiler.TraceAnnotation("train.host_sync"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                    m["step"] = state["step"]
+                    m["wall_s"] = time.perf_counter() - t0
+                    self.history.append(m)
+                    if tel.enabled:
+                        loss_g.set(m["total_loss"])
+                        gnorm_g.set(m["grad_norm"])
+                        tel.events.emit(
+                            "step",
+                            step=int(state["step"]),
+                            loss=m["total_loss"],
+                            grad_norm=m["grad_norm"],
+                            wall_s=m["wall_s"],
+                            phase=int(phase),
+                            metrics={
+                                k: v for k, v in m.items()
+                                if k not in ("step", "wall_s")
+                            },
+                        )
+                    if log:
+                        # only total_loss/grad_norm are guaranteed — model
+                        # metrics dicts need not include a 'loss' key
+                        shown = m.get("loss", m["total_loss"])
+                        log(
+                            f"step {state['step']:>5d}  loss {shown:.4f}  "
+                            f"gnorm {m['grad_norm']:.3f}  t {m['wall_s']:.1f}s"
+                        )
         if res is not None:
             # drain the lag-one deferred guard check (may recover: the
             # returned state can sit behind the loop's nominal target)
