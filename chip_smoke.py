@@ -6,7 +6,10 @@
 One chip: the two main-path Pallas kernels (``ef_update`` and
 ``pack_ef_cast`` with a bf16 wire), compiled for the chip, are checked
 against their jnp references on one seeded 25 MiB bucket whose length is not
-a whole number of blocks.  Then gpt2-paper at its published width (12
+a whole number of blocks.  The fused causal attention of ``attn_train`` is
+checked against the q-chunked scan at gpt2-paper's full attention shape
+(batch 8, seq 1024, 12 heads of 64), output and gradients, each against a
+float32 run of the scan.  Then gpt2-paper at its published width (12
 layers, d_model 768, vocab 50257; random weights from ``--seed``) trains
 with COVAP at interval 4, seq 1024, global batch 8, on the synthetic loader:
 8 steps on the default path (post-backward all-reduce sync, which runs
@@ -126,6 +129,55 @@ def check_kernels(seed: int, n: int = BUCKET_ELEMS) -> None:
               f"{w_err:.3f} bf16 ulp (wire) vs ref")
 
 
+def check_attention(cfg, seed: int, *, batch: int = 8,
+                    seq: int = SEQ) -> dict:
+    """``attn_train`` on the fused kernel path against the q-chunked scan,
+    both in the configuration's compute dtype, and each against the scan in
+    float32: the output and the gradients of ``sum(y * dy)`` w.r.t. the
+    input and every projection weight.  Returns the gaps by leaf."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import attention
+
+    check(attention.takes_flash(cfg, seq, 0),
+          f"{cfg.name} at seq {seq} does not take the fused kernel")
+    kp, kx, kd = jax.random.split(jax.random.PRNGKey(seed), 3)
+    params = attention.attn_init(kp, cfg, jnp.float32)
+    x = jax.random.normal(kx, (batch, seq, cfg.d_model), jnp.float32)
+    dy = jax.random.normal(kd, (batch, seq, cfg.d_model), jnp.float32)
+
+    def run(c, fused: bool):
+        def y_and_grads(params, x):
+            y, pull = jax.vjp(
+                lambda p, x: attention.attn_train(p, x, c).astype(
+                    jnp.float32), params, x)
+            return y, pull(dy)
+
+        takes = attention.takes_flash
+        attention.takes_flash = lambda *a: fused
+        try:
+            compiled = jax.jit(y_and_grads).lower(params, x).compile()
+        finally:
+            attention.takes_flash = takes
+        if fused:
+            require_kernel(compiled.as_text(), "fused attention")
+        y, (gp, gx) = compiled(params, x)
+        return {"y": y, "dx": gx, **{f"d{k}": v for k, v in gp.items()}}
+
+    want = run(cfg.with_(compute_dtype="float32"), False)
+    gaps = {}
+    for name, fused in (("fused", True), ("scan", False)):
+        got = run(cfg, fused)
+        gaps[name] = {k: rel_l2([got[k]], [want[k]]) for k in want}
+    for k in want:
+        f, s = gaps["fused"][k], gaps["scan"][k]
+        print(f"[attention] {k}: rel L2 gap to f32 scan: fused {f:.3e}, "
+              f"scan {s:.3e}")
+        check(f <= BF16_TOL, f"fused attention {k}: rel L2 gap {f}")
+    return gaps
+
+
 def make_batches(cfg, seq: int, global_batch: int, n: int, seed: int):
     from repro.data import DataConfig, make_loader
 
@@ -216,6 +268,7 @@ def one_chip(cfg, *, seed: int, seq: int = SEQ, global_batch: int = 8,
     from repro.train.trainer import TrainConfig
 
     check_kernels(seed, bucket_elems)
+    check_attention(cfg, seed, seq=seq)
     model = build_model(cfg)
     batches = make_batches(cfg, seq, global_batch, 8, seed)
     for name, arena, steps in (("covap I=4 default", False, 8),

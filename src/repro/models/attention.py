@@ -1,6 +1,8 @@
 """Attention: MHA/GQA/MQA with RoPE, q-chunked streaming softmax (bounded
 memory at 32k prefill), sliding-window and softcap variants, and a KV-cache
 decode path (rolling cache for windowed layers -> O(window) state at 500k).
+On the chip, full causal MHA layers run a fused flash-attention kernel
+(``kernels/flash_attention.py``) in place of the q-chunked scan.
 """
 from __future__ import annotations
 
@@ -10,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels import common, flash_attention
 from .layers import linear_init, rope, softcap, truncated_normal_init
 
 NEG_INF = -2.0e38
@@ -63,17 +66,35 @@ def _scores_softmax_value(q, k, v, mask, cfg):
     return jnp.einsum("bkgqt,btkh->bqkgh", p, v)
 
 
+def takes_flash(cfg, seq: int, window: int) -> bool:
+    """Whether :func:`attn_train` runs the fused causal kernel: on the chip,
+    for full causal attention with as many key/value heads as query heads,
+    no logit softcap, and a shape the kernel takes (a sequence of whole
+    128-row tiles; ``flash_attention.supports``).  Every other layer (and
+    every run off the chip) takes the q-chunked scan."""
+    return (not common.INTERPRET and window == 0 and cfg.attn_softcap == 0
+            and cfg.num_kv_heads == cfg.num_heads
+            and flash_attention.supports(seq, cfg.num_heads, cfg.head_dim))
+
+
 def attn_train(params, x, cfg, *, window: int = 0) -> jax.Array:
-    """Causal self-attention over a full sequence, q-chunked.
+    """Causal self-attention over a full sequence: the fused kernel where
+    :func:`takes_flash` allows it, else q-chunked.
 
     ``window > 0`` restricts to a sliding window (j in (i-window, i])."""
     B, S, d = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // K
+    cd = jnp.dtype(cfg.compute_dtype)
     q, k, v = _qkv(params, x, cfg)
     positions = jnp.arange(S)[None, :]
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
+    if takes_flash(cfg, S, window):
+        with jax.named_scope("attention"):
+            out = flash_attention.causal_attention(
+                *(t.reshape(B, S, H * hd) for t in (q, k, v)), num_heads=H)
+        return jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(cd))
     q = q.reshape(B, S, K, G, hd)
 
     chunk = min(cfg.attn_chunk, S)
@@ -100,7 +121,6 @@ def attn_train(params, x, cfg, *, window: int = 0) -> jax.Array:
             1, 0, 2, 3, 4, 5)
         _, outs = lax.scan(body, (), (q_chunks, jnp.arange(n_chunks)))
         out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H * hd)
-    cd = jnp.dtype(cfg.compute_dtype)
     return jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(cd))
 
 

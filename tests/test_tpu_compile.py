@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.ef_covap import ef_update
+from repro.kernels.flash_attention import causal_attention
 from repro.kernels.pack_ef_cast import pack_ef_cast
 from repro.kernels.quantize import dequantize_fp8, quantize_fp8
 from repro.kernels.sign_compress import sign_compress
@@ -94,6 +95,33 @@ KERNELS = {
 }
 
 
+# gpt2-paper's attention at seq 1024 and batch 8: 12 heads of 64
+ATTN_SHAPE, ATTN_HEADS = (8, 1024, 12 * 64), 12
+
+
+def _attention_fwd(q, k, v):
+    return causal_attention(q, k, v, num_heads=ATTN_HEADS, interpret=False)
+
+
+def _attention_fwd_bwd(q, k, v):
+    o, pull = jax.vjp(_attention_fwd, q, k, v)
+    return pull(o)
+
+
+def _kernel_calls(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("fn", [_attention_fwd, _attention_fwd_bwd],
+                         ids=["fwd", "fwd_bwd"])
+def test_flash_attention_lowers_for_v5e(fn, one_chip):
+    """The fused causal attention kernels at gpt2-paper's width: the
+    forward is one kernel, the backward one more."""
+    arg = _spec(ATTN_SHAPE, jnp.bfloat16, one_chip)
+    text = jax.jit(fn).lower(arg, arg, arg).compile().as_text()
+    assert _kernel_calls(text) == (1 if fn is _attention_fwd else 2)
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_lowers_for_v5e(name, one_chip):
     fn, kinds = KERNELS[name]
@@ -112,11 +140,13 @@ def test_kernel_lowers_for_v5e(name, one_chip):
 def test_gpt2_paper_covap_phase_step_fits_one_v5e(arena, one_chip, monkeypatch):
     """The full-width gpt2-paper phase-0 COVAP step at seq 1024 and batch 8
     compiles for one v5e chip with the fused EF kernel (``ef_update``, or
-    ``pack_ef_cast`` on the arena path) inside, and fits its HBM."""
+    ``pack_ef_cast`` on the arena path) and the fused attention kernels
+    inside, and fits its HBM."""
     from repro.configs import get_config
     from repro.core import build_plan
-    from repro.kernels import common, ef_covap, pack_ef_cast as pack
-    from repro.models import build_model
+    from repro.kernels import common, ef_covap, flash_attention
+    from repro.kernels import pack_ef_cast as pack
+    from repro.models import attention, build_model
     from repro.optim import adamw
     from repro.train.trainer import TrainConfig, build_train_step, make_compressor
 
@@ -124,7 +154,10 @@ def test_gpt2_paper_covap_phase_step_fits_one_v5e(arena, one_chip, monkeypatch):
     monkeypatch.setattr(common, "INTERPRET", False)
     monkeypatch.setattr(ef_covap, "INTERPRET", False)
     monkeypatch.setattr(pack, "INTERPRET", False)
-    model = build_model(get_config("gpt2-paper"))
+    monkeypatch.setattr(flash_attention, "INTERPRET", False)
+    cfg = get_config("gpt2-paper")
+    assert attention.takes_flash(cfg, 1024, 0)
+    model = build_model(cfg)
     opt = adamw(1e-4)
     tc = TrainConfig(compressor="covap", interval=4, arena=arena)
     comp = make_compressor(tc)
@@ -145,7 +178,14 @@ def test_gpt2_paper_covap_phase_step_fits_one_v5e(arena, one_chip, monkeypatch):
         place(params), place(opt_state), place(comp_state), place(batch),
         _spec((), jnp.int32, one_chip),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the layer scans compile one body each: the forward kernel, and its
+    # remat recompute with the backward kernel
+    attention_calls = [line for line in text.splitlines()
+                       if 'custom_call_target="tpu_custom_call"' in line
+                       and "attention_kernel" in line]
+    assert len(attention_calls) == 3
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
