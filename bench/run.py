@@ -47,6 +47,8 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 # libtpu would otherwise write its logs under a fixed /tmp path
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
+from bench.flops import param_elements, train_flops_per_token  # noqa: E402
+
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
@@ -247,10 +249,39 @@ class Job:
                    for d in self.devices)
 
 
+def reader_ctx(arch: dict, job: dict, chips: int, steps: int,
+               peak: dict) -> dict:
+    """What every per-layer reader is handed beside the reduced trace."""
+    tokens = job["global_batch"] * job["seq_len"] // chips
+    return {
+        "steps": steps, "arch": arch, "seq_len": job["seq_len"],
+        "tokens_per_chip_step": tokens,
+        "hbm_bytes_per_s": peak["hbm_bytes_per_s"],
+        "bf16_flops": peak["bf16_flops"],
+        "ef_elements_per_step": param_elements(arch),
+        "model_flops_per_chip_step":
+            train_flops_per_token(arch, job["seq_len"]) * tokens,
+    }
+
+
 def read_per_layer(listed: list, reduced, ctx: dict) -> dict:
-    """Each listed metric by its reader, ``bench/metrics/<name>.py``.  A
-    reader that finds nothing to read returns ``None``, and the metric is
-    left out of the result: it is never reported as 0."""
+    """Each listed metric by its reader, ``bench/metrics/<name>.py``,
+    called as ``read(reduced, ctx)``.  A reader that finds nothing to read
+    returns ``None``, and the metric is left out of the result: it is never
+    reported as 0.  ``ctx`` (``reader_ctx``) holds:
+
+    * ``steps``: the training steps in the traced window;
+    * ``arch``: the configuration dict (``bench/configs/<name>.json``'s
+      ``config``), whose shapes ``bench/flops.py`` counts;
+    * ``seq_len``: the sequence length of the cell's job;
+    * ``tokens_per_chip_step``: the tokens one chip trains a step;
+    * ``hbm_bytes_per_s``, ``bf16_flops``: the chip's peaks
+      (``bench/peaks.py``);
+    * ``ef_elements_per_step``: the gradient elements the error-feedback
+      kernel must stream a step (``flops.param_elements``);
+    * ``model_flops_per_chip_step``: the model FLOPs one chip does a step
+      (``flops.train_flops_per_token`` times ``tokens_per_chip_step``).
+    """
     out = {}
     for m in listed:
         reader = load_module(
@@ -320,9 +351,11 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
 
     from bench import correctness
     from bench import trace as tracing
-    from bench.flops import param_elements, train_flops_per_token
     from repro.launch.compile_cache import enable_compile_cache
 
+    # refuses, before anything is built, a configuration it cannot count
+    flops = train_flops_per_token(cell["config"]["config"],
+                                  cell["traffic"]["seq_len"])
     log(f"[cache] {enable_compile_cache()}")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     reference = importlib.import_module(cell["reference"])
@@ -353,12 +386,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
             reduced = tracing.reduce(tdir)
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
-        ctx = {"steps": steps, "hbm_bytes_per_s": peak["hbm_bytes_per_s"],
-               "bf16_flops": peak["bf16_flops"],
-               "ef_elements_per_step": param_elements(job.arch),
-               "model_flops_per_chip_step": train_flops_per_token(
-                   job.arch, job.job["seq_len"]) * tokens_per_step
-               / len(devices)}
+        ctx = reader_ctx(job.arch, job.job, len(devices), steps, peak)
         metrics = read_per_layer(cell["per_layer"], reduced, ctx)
         busy = [tracing.busy_ns(reduced, c) / 1e9 for c in reduced.chips]
         extra = {"busy_s": sum(busy) / len(busy),
@@ -376,7 +404,6 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
         window_s = end - t
         log(host_report(job.stamps, end, job.job["log_every"], gcw))
         tps = steps * tokens_per_step / window_s
-        flops = train_flops_per_token(job.arch, job.job["seq_len"])
         values = {
             "tokens_per_s": tps,
             "mfu": 100.0 * tps * flops / (len(devices) * peak["bf16_flops"]),

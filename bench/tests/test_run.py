@@ -75,3 +75,55 @@ def test_host_report_finds_the_stretch_that_holds_a_stall():
     line = bench_run.host_report(stamps, stamps[-1] + 0.01, 10, gcw)
     assert "[90.0, 2100.0, 100.0] ms, longest ending at step 20" in line
     assert "longest dispatch wait 2010.0 ms before step 15" in line
+
+
+def test_readers_receive_the_configuration_and_the_shapes(monkeypatch):
+    """A traced run hands every reader the configuration, the sequence
+    length and the tokens one chip trains a step, beside the counts that
+    ``bench/flops.py`` makes of them."""
+    from bench import flops
+    from bench import run as bench_run
+    from bench import trace as T
+    from bench.tests import tiny
+
+    ops = [T.Op("%fusion.1 = f32[] fusion()", 0, 50)]
+    T.set_self_times(ops)
+    monkeypatch.setattr(T, "reduce", lambda tdir: T.Trace(
+        {0: ops}, [("window", 0, 100)], (0, 100)))
+    seen = {}
+    monkeypatch.setattr(bench_run, "read_per_layer",
+                        lambda listed, reduced, ctx: seen.update(ctx) or {})
+    r = bench_run.run_cell(tiny.cell(), 2**31 + 29, 0.2, True,
+                           tiny.devices(), tiny.PEAK)
+    assert r["correct"], r["checks"]
+    tokens = tiny.TRAFFIC["global_batch"] * tiny.TRAFFIC["seq_len"]
+    assert seen["arch"] == tiny.ARCH
+    assert seen["seq_len"] == tiny.TRAFFIC["seq_len"] == 64
+    assert seen["tokens_per_chip_step"] == tokens == 512
+    assert seen["steps"] == 8
+    assert seen["ef_elements_per_step"] == flops.param_elements(tiny.ARCH)
+    assert seen["model_flops_per_chip_step"] == \
+        flops.train_flops_per_token(tiny.ARCH, 64) * tokens
+
+
+def test_reader_context_divides_the_tokens_over_the_chips():
+    from bench import run as bench_run
+    from bench.tests.tiny import PEAK
+
+    with open(os.path.join(ROOT, "bench", "configs", "gpt2-paper.json")) as f:
+        arch = json.load(f)["config"]
+    job = {"global_batch": 8, "seq_len": 1024}
+    ctx = bench_run.reader_ctx(arch, job, 4, 2, PEAK)
+    assert ctx["tokens_per_chip_step"] == 2048
+    # 1,024,307,712 FLOPs a token, 2048 tokens a chip a step
+    assert ctx["model_flops_per_chip_step"] == 1_024_307_712 * 2048
+    assert ctx["ef_elements_per_step"] == 190_532_352
+
+
+def test_a_configuration_that_cannot_be_counted_stops_the_set_up():
+    from bench import run as bench_run
+    from bench.tests import tiny
+
+    with pytest.raises(ValueError, match="sliding_window"):
+        bench_run.run_cell(tiny.cell(sliding_window=16), 2**31 + 31, 0.2,
+                           False, tiny.devices(), tiny.PEAK)
