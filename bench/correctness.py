@@ -44,11 +44,31 @@ def _norms(xs):
     return [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))) for x in xs]
 
 
+@jax.jit
+def _diff_norms(xs, ys):
+    return [jnp.sqrt(jnp.sum(jnp.square((x - y).astype(jnp.float32))))
+            for x, y in zip(xs, ys)]
+
+
+def _keyed(flat, norms) -> dict:
+    return {jax.tree_util.keystr(p): float(n) for (p, _), n in zip(flat, norms)}
+
+
 def leaf_norms(tree) -> dict:
     """The norm of each leaf, keyed by its path."""
     flat = jax.tree_util.tree_leaves_with_path(tree)
-    norms = _norms([x for _, x in flat])
-    return {jax.tree_util.keystr(p): float(n) for (p, _), n in zip(flat, norms)}
+    return _keyed(flat, _norms([x for _, x in flat]))
+
+
+def change_norms(tree, start) -> dict:
+    """The norm of each leaf's change from ``start``, keyed by its path:
+    one reduction that returns the norms alone, so no tree of differences
+    is held beside the two."""
+    if jax.tree.structure(tree) != jax.tree.structure(start):
+        raise ValueError("the two trees differ in structure")
+    flat = jax.tree_util.tree_leaves_with_path(tree)
+    return _keyed(flat, _diff_norms([x for _, x in flat],
+                                    jax.tree.leaves(start)))
 
 
 def leaf_gaps(prog: dict, ref: dict, keys) -> dict:

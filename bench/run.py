@@ -198,9 +198,7 @@ class Job:
     def first_cycle(self, compared: int) -> dict:
         """One phase cycle, one step per ``Trainer.run`` call, with the
         readings of the first ``compared`` steps."""
-        import jax
-
-        from bench.correctness import leaf_norms
+        from bench.correctness import change_norms, leaf_norms
 
         b1 = self.job["optimizer"]["b1"]
         losses, out = [], {}
@@ -213,8 +211,7 @@ class Job:
             if step == compared - 1:
                 start = self.reference.init_params(self.arch, self.seed,
                                                    out_shardings=self.rep)
-                out["change"] = leaf_norms(jax.tree.map(
-                    lambda a, b: a - b, self.state["params"], start))
+                out["change"] = change_norms(self.state["params"], start)
                 out["resid"] = leaf_norms(self.chip_mean(self.state["comp"]))
                 del start
         out["losses"] = losses[:compared]

@@ -27,7 +27,7 @@ sys.path[:0] = [{root!r}, os.path.join({root!r}, "src")]
 from bench import faults, run
 from bench.tests import tiny
 out = {{}}
-for fault in (None, "no_exchange", "half_batch", "no_feedback"):
+for fault in (None, "no_exchange", "unchanged", "half_batch", "no_feedback"):
     with faults.planted(fault):
         r = run.run_cell(tiny.cell(chips=4), 2**31 + 23, 0.2, False,
                          tiny.devices(4), tiny.PEAK)
@@ -43,5 +43,5 @@ def test_faults_on_four_devices():
                          env=env, capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]
     got = json.loads(out.stdout.strip().splitlines()[-1])
-    assert got == {"None": True, "no_exchange": False, "half_batch": False,
-                   "no_feedback": False}
+    assert got == {"None": True, "no_exchange": False, "unchanged": False,
+                   "half_batch": False, "no_feedback": False}

@@ -1,5 +1,6 @@
 """A tiny training cell for the CPU tests: the harness's whole path at a
 size a test run can hold."""
+import contextlib
 import os
 
 import jax
@@ -50,3 +51,18 @@ def devices(n: int = 1):
 
 
 PEAK = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+@contextlib.contextmanager
+def donating():
+    """The trainer's steps built to donate their params, optimizer and
+    compressor state, whatever the trainer asks for, inside the block."""
+    from repro.train import trainer
+
+    build = trainer.build_train_step
+    trainer.build_train_step = \
+        lambda *a, **kw: build(*a, **dict(kw, donate=True))
+    try:
+        yield
+    finally:
+        trainer.build_train_step = build

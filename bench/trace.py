@@ -8,8 +8,9 @@ executed HLO instruction, named by its full HLO text.  Those events carry no
 name stack there, so each op takes its JAX name stack (``tf_op``) from the
 JSON export, joined on the device and the op's ``device_offset_ps``.  Host
 spans are the ``jax.profiler.TraceAnnotation`` events whose names start with
-``bench.``, which the benchmark opens around its own calls.  Device and host
-events share one clock.
+``bench.``, which the benchmark opens around its own calls (kept without the
+prefix), and the trainer's own ``train_step`` and ``train.*`` spans (kept
+whole).  Device and host events share one clock.
 """
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ import re
 OPS_LINE = "XLA Ops"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 SPAN_PREFIX = "bench."
+PROGRAM_SPAN = re.compile(r"^train(_step$|\.)")
 _INSTR = re.compile(r"^%?([\w.\-]+) = ")
 _OPCODE = re.compile(r"= .*?\s([a-z][a-z0-9\-]*)\(")
 
@@ -50,7 +52,7 @@ class Op:
 @dataclasses.dataclass
 class Trace:
     chips: dict            # chip index -> [Op], sorted by start
-    spans: list            # (name, start_ns, end_ns) of bench host spans
+    spans: list            # (name, start_ns, end_ns) of the host spans kept
     window: tuple          # (start_ns, end_ns) of the span named ``window``
 
     @property
@@ -117,6 +119,27 @@ def gaps(intervals, lo: int, hi: int) -> list:
     return subtract([(lo, hi)], intervals)
 
 
+def innermost(spans, names) -> list:
+    """The merged stretches in which the innermost open span of the trainer
+    (``train_step``, ``train.*``) is one of ``names``: each such span less
+    the trainer's shorter spans nested in it."""
+    kin = [(s, e) for n, s, e in spans if PROGRAM_SPAN.match(n)]
+    out = []
+    for n, s, e in spans:
+        if n in names:
+            out += subtract([(s, e)], [(cs, ce) for cs, ce in kin
+                                       if s <= cs and ce <= e
+                                       and ce - cs < e - s])
+    return union(out)
+
+
+def host_name(name: str) -> str | None:
+    """The name a host event is kept under, or ``None`` where it is not."""
+    if name.startswith(SPAN_PREFIX):
+        return name[len(SPAN_PREFIX):]
+    return name if PROGRAM_SPAN.match(name) else None
+
+
 def _stacks(json_path: str) -> dict:
     """(chip, device_offset_ps) -> tf_op name stack, from the JSON export."""
     with gzip.open(json_path, "rt") as f:
@@ -171,10 +194,11 @@ def reduce(trace_dir: str, window: str = "window") -> Trace:
                 ops.sort(key=lambda o: o.start)
                 chips[chip] = ops
             elif plane.name.startswith("/host"):
-                spans.extend(
-                    (ev.name[len(SPAN_PREFIX):], int(ev.start_ns),
-                     int(ev.start_ns + ev.duration_ns))
-                    for ev in line.events if ev.name.startswith(SPAN_PREFIX))
+                for ev in line.events:
+                    name = host_name(ev.name)
+                    if name is not None:
+                        spans.append((name, int(ev.start_ns),
+                                      int(ev.start_ns + ev.duration_ns)))
     wins = [(s, e) for n, s, e in spans if n == window]
     if len(wins) != 1:
         raise ValueError(f"expected one host span {SPAN_PREFIX}{window}, "
@@ -191,7 +215,7 @@ def busy_ns(trace: Trace, chip: int) -> int:
 
 
 def span_at(trace: Trace, t: int) -> str:
-    """The innermost bench host span open at ``t``, or ``none``."""
+    """The innermost host span open at ``t``, or ``none``."""
     best = None
     for name, s, e in trace.spans:
         if s <= t < e and (best is None or e - s < best[2] - best[1]):
@@ -202,7 +226,7 @@ def span_at(trace: Trace, t: int) -> str:
 def breakdown(trace: Trace, top: int = 10) -> dict:
     """The device ops that took most time (self time summed per instruction
     name, averaged over chips) and the longest idle gaps, each labelled by
-    the bench span the host had open."""
+    the innermost host span the host had open."""
     lo, hi = trace.window
     per_op: dict[str, int] = {}
     idle = []
