@@ -72,7 +72,7 @@ def _overhead_gate(smoke: bool) -> tuple:
     from repro.data import DataConfig, make_loader
     from repro.models import build_model
     from repro.optim import sgd
-    from repro.train.trainer import TrainConfig, Trainer
+    from repro.train.trainer import TrainConfig, Trainer, copy_train_state
 
     cfg = get_reduced("gpt2-paper").with_(vocab_size=256)
     model = build_model(cfg)
@@ -86,12 +86,15 @@ def _overhead_gate(smoke: bool) -> tuple:
 
     steps = 8 if smoke else 12
     trials = 7 if smoke else 11
-    tr.run(state, loader, steps=2, log=None)      # compile both phases
-    tr.run(state, loader, steps=2, log=None, guards=True)   # + guard jits
+    # run() consumes the state it is handed: every run gets a copy
+    tr.run(copy_train_state(state), loader, steps=2, log=None)  # compile
+    tr.run(copy_train_state(state), loader, steps=2, log=None,
+           guards=True)                                    # + guard jits
 
     def timed(guards) -> float:
+        start = jax.block_until_ready(copy_train_state(state))
         t0 = time.perf_counter()
-        out = tr.run(state, loader, steps=steps, log=None, guards=guards)
+        out = tr.run(start, loader, steps=steps, log=None, guards=guards)
         # settle async dispatch: without this the bare arm measures only
         # the host loop, and the guarded arm's per-step sync looks like a
         # 200% "overhead" that is really the compute wall itself

@@ -166,7 +166,7 @@ def _overhead_gate(td: str, smoke: bool) -> tuple:
     from repro.models import build_model
     from repro.obs import NULL_TELEMETRY, Telemetry
     from repro.optim import sgd
-    from repro.train.trainer import TrainConfig, Trainer
+    from repro.train.trainer import TrainConfig, Trainer, copy_train_state
 
     cfg = get_reduced("gpt2-paper").with_(vocab_size=256)
     model = build_model(cfg)
@@ -187,12 +187,14 @@ def _overhead_gate(td: str, smoke: bool) -> tuple:
     # per side (both sides see the same noise regime; min discards it)
     steps = 4 if smoke else 8
     trials = 5 if smoke else 9
-    tr.run(state, loader, steps=2, log=silent)   # compile both phases
+    # run() consumes the state it is handed: every run gets a copy
+    tr.run(copy_train_state(state), loader, steps=2, log=silent)  # compile
     tel = Telemetry(os.path.join(td, "overhead"))
 
     def timed(telemetry) -> float:
+        start = jax.block_until_ready(copy_train_state(state))
         t0 = time.perf_counter()
-        tr.run(state, loader, steps=steps, log=silent, telemetry=telemetry)
+        tr.run(start, loader, steps=steps, log=silent, telemetry=telemetry)
         return (time.perf_counter() - t0) / steps
 
     def measure() -> tuple:

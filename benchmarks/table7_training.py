@@ -16,7 +16,7 @@ from repro.configs import get_reduced
 from repro.data import DataConfig, make_loader
 from repro.models import build_model
 from repro.optim import adamw
-from repro.train.trainer import TrainConfig, Trainer
+from repro.train.trainer import TrainConfig, Trainer, copy_train_state
 
 from .common import row
 
@@ -50,8 +50,10 @@ def run():
         # compile all phases first
         warm = next(loader)
         for ph in range(tr.num_phases):
-            tr._phase_fn(ph)(state["params"], state["opt"], state["comp"],
-                             warm, jnp.int32(ph))
+            # the step consumes (donates) its state: warm up on a copy
+            warm_state = copy_train_state(state)
+            tr._phase_fn(ph)(warm_state["params"], warm_state["opt"],
+                             warm_state["comp"], warm, jnp.int32(ph))
         t0 = time.perf_counter()
         losses = []
         for _ in range(STEPS):

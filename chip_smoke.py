@@ -208,7 +208,7 @@ def train(model, optimizer, tc, batches, *, seed: int, mesh=None,
                     for k in ("params", "opt", "comp")},
                  "step": state["step"]}
         batches = [jax.device_put(b, dp) for b in batches]
-    params0 = state["params"]
+    params0 = jax.tree.map(jnp.copy, state["params"])  # run() consumes state
     t0 = time.perf_counter()
     for phase in range(tr.num_phases):
         compiled = tr._phase_fn(phase).lower(

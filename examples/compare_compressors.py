@@ -14,7 +14,7 @@ from repro.configs import get_reduced
 from repro.data import DataConfig, make_loader
 from repro.models import build_model
 from repro.optim import adamw
-from repro.train.trainer import TrainConfig, Trainer
+from repro.train.trainer import TrainConfig, Trainer, copy_train_state
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=30)
@@ -35,8 +35,10 @@ for scheme in args.schemes.split(","):
     # warm-up/compile every phase executable outside the timed region
     batch = next(loader)
     for ph in range(tr.num_phases):
-        tr._phase_fn(ph)(state["params"], state["opt"], state["comp"],
-                         batch, jnp.int32(ph))
+        # the step consumes (donates) its state: warm up on a copy
+        warm_state = copy_train_state(state)
+        tr._phase_fn(ph)(warm_state["params"], warm_state["opt"],
+                         warm_state["comp"], batch, jnp.int32(ph))
     t0 = time.perf_counter()
     losses = []
     for i in range(args.steps):

@@ -2,15 +2,18 @@
 
 The :class:`ResilienceRuntime` brackets each step:
 
-* ``pre_step`` — pin the clean incoming state as a rollback point when a
-  new batch window opens (a *reference*, not a copy: JAX arrays are
-  immutable), write the guard-owned checkpoint on its cadence, then let
-  the fault injector corrupt the step's inputs.  Snapshot-before-inject
-  is load-bearing: skip-step must restore the state as it was before the
-  fault, not a faithfully-corrupted copy.  At most TWO states are ever
-  pinned (current + previous window): pinning every step's state keeps
-  the allocator from recycling step buffers and makes the training step
-  itself ~40% slower — the dominant guard cost, ahead of any host sync.
+* ``pre_step`` — pin a device copy of the clean incoming state as a
+  rollback point when a new batch window opens (a copy, because the
+  phase executables donate — consume — the state they are handed), write
+  the guard-owned checkpoint on its cadence, then let the fault injector
+  corrupt the step's inputs.  Snapshot-before-inject is load-bearing:
+  skip-step must restore the state as it was before the fault, not a
+  faithfully-corrupted copy.  At most TWO states are ever pinned
+  (current + previous window), copied only when a window opens (every
+  ``sync_every`` steps): pinning every step's state would cost a state
+  copy a step and keeps the allocator from recycling step buffers
+  (per-step pins once made the training step itself ~40% slower — the
+  dominant guard cost, ahead of any host sync).
 * ``post_step`` — run the guard battery on the step's host-side metrics
   (on ``check_every`` cadence) and, on a trip, climb the escalation
   ladder.  Checks are **deferred and batched**: step ``N``'s device
@@ -29,10 +32,10 @@ The :class:`ResilienceRuntime` brackets each step:
   (``Guards.residual_async``) so the batched flush finds it already
   computed.  ``finalize`` drains the pending batch when the loop ends:
 
-  1. **skip-step** — discard the poisoned update by restoring the batch
-     window's start snapshot (equivalent to zeroed updates: params,
-     optimizer moments and EF residual all revert; the batches are
-     consumed).  With ``sync_every=1`` this is exactly the tripped
+  1. **skip-step** — discard the poisoned update by restoring (a copy
+     of) the batch window's start snapshot (equivalent to zeroed
+     updates: params, optimizer moments and EF residual all revert; the
+     batches are consumed).  With ``sync_every=1`` this is exactly the tripped
      step's pre-state; larger windows also discard up to
      ``sync_every - 1`` clean neighbour steps.  Heals transient
      corruption.
@@ -68,6 +71,8 @@ from __future__ import annotations
 
 import time
 from typing import Any
+
+from repro.train.trainer import copy_train_state
 
 from .faults import FaultInjector, FaultPlan, as_fault_plan
 from .guards import GuardConfig, Guards, GuardTrip, as_guard_config
@@ -110,12 +115,13 @@ class ResilienceRuntime:
         self.telemetry = as_telemetry(telemetry)
         if self.injector is not None:
             self.injector.attach_telemetry(self.telemetry)
-        # rollback points: at most TWO pinned states — the current batch
-        # window's start and the previous (still-unflushed) window's.
-        # Pinning one state per step (the obvious design) makes the
-        # TRAINING STEP itself ~40% slower: every live snapshot blocks the
-        # allocator from recycling the step's buffers, so each step pays
-        # fresh cold-page allocations.  (step, pre-step state) | None:
+        # rollback points: at most TWO pinned states — device copies of
+        # the current batch window's start and the previous (still-
+        # unflushed) window's.  Pinning one state per step (the obvious
+        # design) would copy the whole state every step, and every live
+        # snapshot blocks the allocator from recycling the step's buffers
+        # (per-step pins once made the TRAINING STEP itself ~40% slower).
+        # (step, pre-step state) | None:
         self._win: tuple[int, dict] | None = None
         self._prev_win: tuple[int, dict] | None = None
         # deferred checks, flushed in batches of sync_every:
@@ -156,12 +162,12 @@ class ResilienceRuntime:
         ):
             state = self._save_checkpoint(state)
         # a new batch window opens when the queue is empty (run start /
-        # just recovered) or full (this post_step will flush it): pin this
-        # step's pre-state as the window's rollback point (a reference,
-        # not a copy — JAX arrays are immutable)
+        # just recovered) or full (this post_step will flush it): pin a
+        # copy of this step's pre-state as the window's rollback point —
+        # the step about to run consumes the state it is handed
         if not self._pending or len(self._pending) >= cfg.sync_every:
             self._prev_win = self._win
-            self._win = (step, state)
+            self._win = (step, copy_train_state(state))
         if self.injector is not None:
             from .faults import InjectedCrash
 
@@ -283,7 +289,8 @@ class ResilienceRuntime:
         tripped step: exactly its pre-step state when ``sync_every=1``,
         else the start of the batch window it ran in (discarding up to
         ``sync_every - 1`` clean neighbours — the price of pinning only
-        two rollback states, see ``__init__``)."""
+        two rollback states, see ``__init__``).  Hands out a copy: the
+        next step consumes it, and the snapshot stays pinned."""
         best = None
         for w in (self._prev_win, self._win):
             if w is not None and w[0] <= step:
@@ -291,7 +298,7 @@ class ResilienceRuntime:
                     best = w
         if best is None:
             raise RecoveryError("no pre-step snapshot to skip back to")
-        return best[1]
+        return copy_train_state(best[1])
 
     def _flush(self, step: int) -> dict:
         from repro.runtime.transitions import carry_comp_state

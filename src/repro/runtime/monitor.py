@@ -157,12 +157,28 @@ def _blocked(fn: Callable, *args) -> Callable[[], None]:
     return run
 
 
+def _chained(step: Callable, params, opt, comp, batch,
+             step_no) -> Callable[[], None]:
+    """Time the trainer's own (donating) phase executable: each call
+    consumes the state the call before it returned, starting from a
+    private copy, so the caller's live state is never donated."""
+    carry = jax.block_until_ready(jax.tree.map(jnp.copy, (params, opt, comp)))
+
+    def run():
+        nonlocal carry
+        *carry, metrics = step(*carry, batch, step_no)
+        jax.block_until_ready((carry, metrics))
+
+    return run
+
+
 class PhaseProbe:
     """Measures one phase's comm/compute decomposition on live state.
 
     Three sub-programs, cached after first build:
 
-    * **full** — the trainer's own phase executable (collectives included);
+    * **full** — the trainer's own phase executable (collectives
+      included), chained on a copy of the state since it donates its input;
     * **compute-only** — the identical step built with ``mesh=None`` so
       every collective is elided (``core.comm`` reduces become identities);
     * **schedule-only** — the **dense** schedule's collectives replayed on
@@ -235,7 +251,7 @@ class PhaseProbe:
         else:
             comp_args = args
         res = measure_ccr(
-            _blocked(full, *args),
+            _chained(full, *args),
             _blocked(self._compute_fn(phase), *comp_args),
             step_comm_only=_blocked(self._comm_fn(phase)),
             warmup=self.warmup,

@@ -5,6 +5,7 @@ from .trainer import (
     Trainer,
     build_overlapped_step,
     build_train_step,
+    copy_train_state,
     make_train_state,
     restore_pod_block,
     strip_pod_block,
@@ -15,6 +16,7 @@ __all__ = [
     "Trainer",
     "build_overlapped_step",
     "build_train_step",
+    "copy_train_state",
     "make_train_state",
     "restore_pod_block",
     "strip_pod_block",

@@ -541,6 +541,34 @@ def build_train_step(
     return jitted
 
 
+def _donated_share(tree) -> float:
+    """Share of ``tree``'s bytes whose buffers a donating call consumed
+    (deleted).  Under 1 when some leaf could not alias an output and was
+    kept ("Some donated buffers were not usable")."""
+    leaves = jax.tree.leaves(tree)
+    total = sum(x.nbytes for x in leaves)
+    gone = sum(
+        x.nbytes for x in leaves
+        if isinstance(x, jax.Array) and x.is_deleted()
+    )
+    return gone / total if total else 1.0
+
+
+@jax.jit
+def _copy_arrays(tree):
+    # one dispatch for the whole state; module scope, so it compiles once
+    # per state structure rather than once per caller
+    return jax.tree.map(jnp.copy, tree)
+
+
+def copy_train_state(state: dict) -> dict:
+    """``state`` with fresh device copies of its params, optimizer moments
+    and EF residuals (the step counter is shared): what a caller keeps
+    when it hands the original to :meth:`Trainer.run`, which consumes it."""
+    keys = [k for k in ("params", "opt", "comp") if k in state]
+    return {**state, **_copy_arrays({k: state[k] for k in keys})}
+
+
 def make_train_state(model, optimizer, compressor, plan, key):
     params = model.init(key)
     return {
@@ -572,6 +600,9 @@ class Trainer:
             interval=tc.interval,
         )
         self._steps: dict[int, Callable] = {}
+        # phases whose executable has had its donation read (the
+        # ``train_state_donated_share`` gauge, set on each one's first call)
+        self._donation_read: set[int] = set()
         self.history: list[dict] = []
         self.runtime = None          # AdaptiveRuntime of the last run(), if any
         self.resilience = None       # ResilienceRuntime of the last run(), if any
@@ -680,7 +711,7 @@ class Trainer:
             self._steps[phase] = build_train_step(
                 self.model, self.optimizer, self.compressor, self.plan,
                 phase=phase, mesh=self.mesh, dp_axes=self.dp_axes,
-                clip_norm=self.tc.clip_norm, donate=False,
+                clip_norm=self.tc.clip_norm, donate=True,
                 pod_interval=self.tc.pod_interval,
                 overlap=self.tc.overlap,
             )
@@ -820,6 +851,7 @@ class Trainer:
             interval=self.tc.interval,
         )
         self._steps = {}   # stale executables: new phases compile lazily
+        self._donation_read = set()
         self._flush_fns = {}
         report = None
         if state is not None:
@@ -866,7 +898,14 @@ class Trainer:
         chaos runs; a live :class:`~repro.resilience.ResilienceRuntime`
         passed as ``guards`` keeps its ladder/injector state across
         chunked ``run`` calls.  With both None the loop is the prior
-        path, bit-for-bit."""
+        path, bit-for-bit.
+
+        The phase executables donate the params, optimizer moments and
+        error-feedback residuals, so each step updates them in place and
+        the host can queue the next step while one runs: the ``state``
+        passed in is consumed.  Use the returned state; a caller that
+        needs the input state afterwards passes a copy
+        (:func:`copy_train_state`)."""
         from repro.obs import as_telemetry
         from repro.obs.events import plan_digest
 
@@ -936,7 +975,7 @@ class Trainer:
                 with jax.profiler.TraceAnnotation("train.batch_wait"):
                     batch = next(it)
                 if res is not None:
-                    # snapshot (free: state dicts reference immutable arrays)
+                    # snapshot (a device copy when a check window opens)
                     # -> guard-owned checkpoint -> fault injection
                     state, batch = res.pre_step(state, batch)
                 phase = state["step"] % self.num_phases
@@ -946,11 +985,19 @@ class Trainer:
                 # whole run to feed a diagnostic metric
                 timed = rt is not None and rt.due_next()
                 t_step = time.perf_counter() if timed else 0.0
+                handed = (state["params"], state["opt"], state["comp"])
                 with jax.profiler.TraceAnnotation("train.dispatch"):
                     params, opt, comp, metrics = fn(
-                        state["params"], state["opt"], state["comp"], batch,
-                        jnp.asarray(state["step"], jnp.int32),
+                        *handed, batch, jnp.asarray(state["step"], jnp.int32),
                     )
+                if phase not in self._donation_read:
+                    self._donation_read.add(phase)
+                    tel.registry.gauge(
+                        "train_state_donated_share",
+                        "share of the handed-in train state's bytes the "
+                        "phase executable consumed (donated), first call",
+                        phase=str(phase),
+                    ).set(_donated_share(handed))
                 state = {"params": params, "opt": opt, "comp": comp,
                          "step": state["step"] + 1}
                 steps_c.inc()

@@ -260,6 +260,51 @@ def test_skip_step_restores_pre_fault_state():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_skip_step_under_donating_step_keeps_pinned_snapshot(monkeypatch):
+    """The step consumes the state it is handed, so the rollback point is
+    a pinned copy: a skip-step back to it hands the step a copy of its
+    own and leaves the snapshot alive, and the resumed run equals the
+    unfaulted run fed the same batches."""
+    from repro.resilience.recovery import ResilienceRuntime
+
+    dc = DataConfig(vocab_size=256, seq_len=16, global_batch=4,
+                    corpus_tokens=1 << 12)
+    batches = list(b for b, _ in zip(make_loader(dc), range(16)))
+    skipped = []
+    skip = ResilienceRuntime._skip
+
+    def spy(self, step):
+        restored = skip(self, step)
+        pinned = max((w for w in (self._prev_win, self._win)
+                      if w is not None and w[0] <= step), key=lambda w: w[0])
+        skipped.append((pinned[1], restored))
+        return restored
+
+    monkeypatch.setattr(ResilienceRuntime, "_skip", spy)
+    model, tr, state, _ = _tiny_trainer()
+    tel = Telemetry()
+    healed = tr.run(state, iter(batches), steps=12, log=None, telemetry=tel,
+                    guards={"sync_every": 1}, faults="grad_nan@5")
+    assert tr.resilience.summary()["actions_by_rung"] == {"skip_step": 1}
+    shares = [v for k, v in tel.registry.snapshot().items()
+              if k.startswith("train_state_donated_share")]
+    assert shares and all(v == 1.0 for v in shares)
+
+    def arrays(s):
+        return jax.tree.leaves([s[k] for k in ("params", "opt", "comp")])
+
+    (pinned, restored), = skipped
+    assert all(x.is_deleted() for x in arrays(restored))   # consumed
+    assert not any(x.is_deleted() for x in arrays(pinned))  # still pinned
+    assert int(pinned["step"]) == 5
+    m, t, s, _ = _tiny_trainer()
+    replayed = t.run(s, iter(batches[:5] + batches[7:12]), steps=10,
+                     log=None)
+    assert int(healed["step"]) == int(replayed["step"]) == 10
+    for a, b in zip(arrays(healed), arrays(replayed)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_batched_sync_detection_and_recovery():
     """Default ``sync_every=4`` batches the deferred checks: detection is
     late (up to a full batch window of work is discarded) but still

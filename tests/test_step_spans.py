@@ -12,7 +12,7 @@ from repro.configs import get_reduced
 from repro.data import DataConfig, make_loader
 from repro.models import build_model
 from repro.optim import adamw
-from repro.train.trainer import TrainConfig, Trainer
+from repro.train.trainer import TrainConfig, Trainer, copy_train_state
 
 
 def _setup(overlap="post", log_every=10 ** 9):
@@ -81,7 +81,8 @@ def _host_events(trace_dir):
 def test_run_opens_a_step_span_and_host_spans_on_the_profiler_clock(tmp_path):
     tr, state, loader = _setup(log_every=2)
     it = iter(loader)
-    tr.run(state, it, steps=1, log=None)      # compile outside the trace
+    # compile outside the trace, on a copy: run() consumes its state
+    tr.run(copy_train_state(state), it, steps=1, log=None)
     jax.profiler.start_trace(str(tmp_path))
     try:
         tr.run(state, it, steps=3, log=None)
